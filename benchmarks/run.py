@@ -60,6 +60,8 @@ def main() -> None:
                          "(name, us_per_call, derived)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     only = args.only
 

@@ -11,6 +11,8 @@ sys.path.insert(0, "src")
 
 import jax
 jax.config.update("jax_enable_x64", True)
+from repro.compile_cache import enable_compile_cache
+enable_compile_cache()
 import jax.numpy as jnp
 import numpy as np
 

@@ -44,8 +44,10 @@ Registered backends:
                  Row counts that don't divide the mesh are zero-padded
                  and masked, so non-aligned n works on any device count.
 
-``backend="auto"`` (the config default) resolves per platform at trace
-time: TPU → ``pallas``, anything else → ``xla``.
+``backend="auto"`` (the config default) resolves per platform and block
+dtype at trace time: TPU → ``pallas`` for f32/bf16 blocks and ``xla`` for
+f64 blocks (Mosaic has no f64), anything else → ``xla``
+(``resolve_backend``).
 
 Every executor carries a ``Precision`` policy (``core.precision``): blocks
 are materialized in the data dtype, reductions run in ``accum_dtype``, and
@@ -58,7 +60,6 @@ representably PD at any working precision.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import math
 
 import jax
@@ -77,26 +78,14 @@ DEFAULT_BLOCK_ROWS = 4096
 
 # ------------------------------------------------------------ mesh plumbing
 
-# version-compat: jax.shard_map is top-level only on newer jax
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax ≤ 0.4.x
-    from jax.experimental.shard_map import shard_map
-
-# Pallas calls (and other primitives without a replication rule) need the
-# replication check disabled inside shard_map; the kwarg was renamed
-# check_rep → check_vma across jax versions, so detect it once.
-_SHARD_MAP_PARAMS = inspect.signature(shard_map).parameters
-_NOREP_KWARG = next((k for k in ("check_rep", "check_vma")
-                     if k in _SHARD_MAP_PARAMS), None)
-
-
 def shard_map_norep(f, *, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with the replication check off (version-portably) —
-    required so the Pallas tile kernels can run as the per-shard body."""
-    kwargs = {_NOREP_KWARG: False} if _NOREP_KWARG else {}
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kwargs)
+    """Jitted ``jax.shard_map`` with the varying-manual-axes check off —
+    required so the Pallas tile kernels, which have no replication rule,
+    can run as the per-shard body. Jitted because a ``shard_map`` called
+    eagerly runs its body op by op, compiling every op anew on each
+    call (about a hundred programs per sharded fit)."""
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def data_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
@@ -104,10 +93,8 @@ def data_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
     devs = jax.devices()
     if n_devices is not None:
         devs = devs[:n_devices]
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh((len(devs),), (axis,),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    return jax.make_mesh((len(devs),), (axis,), devices=devs)
+    return jax.make_mesh((len(devs),), (axis,), devices=devs,
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def validated_device_count(
@@ -423,7 +410,28 @@ class PallasOps(KernelOps):
     the dense per-block formula.
     """
 
+    # built by ``backend="auto"``: blocks of a dtype no Mosaic tile takes
+    # (float64) follow ``resolve_backend``'s dtype rule to the dense XLA
+    # formula instead of raising
+    auto: bool = False
+
     name = "pallas"
+
+    def _tiles_take(self, dtype) -> bool:
+        """Whether blocks of ``dtype`` run on the Pallas tiles. Off-TPU the
+        tiles interpret any dtype. On a TPU float64 has no Mosaic lowering:
+        an ``auto`` executor sends it to XLA, an explicit ``pallas`` one
+        raises rather than silently running something else."""
+        if jax.default_backend() != "tpu" or \
+                resolve_backend("auto", dtype) == "pallas":
+            return True
+        if self.auto:
+            return False
+        raise ValueError(
+            f"backend='pallas' got {jnp.dtype(dtype).name} data on a TPU, "
+            f"and the Mosaic tiles have no float64 lowering; set "
+            f"Precision.data_dtype='float32' (or 'bfloat16') to run the "
+            f"tiles, or use backend='auto' or 'xla' for a float64 fit")
 
     def _tile_acc(self, *dtypes) -> str | None:
         """Explicit accumulation dtype name for the tile kernels, or None
@@ -435,6 +443,8 @@ class PallasOps(KernelOps):
     def cross(self, X_test: Array, Z: Array) -> Array:
         from ..kernels import ops as kops
         X_test, Z = self._cast_data(X_test, Z)
+        if not self._tiles_take(jnp.result_type(X_test.dtype, Z.dtype)):
+            return self._gram(X_test, Z)
         acc = self._tile_acc(X_test.dtype, Z.dtype)
         k = self.kernel
         if isinstance(X_test, CsrMatrix):
@@ -474,6 +484,8 @@ class PallasOps(KernelOps):
         # inverse runs in solve_dtype when the policy widens it; the tile
         # then reads M at that precision and accumulates per its acc rule.
         from ..kernels import ops as kops
+        if not self._tiles_take(B.dtype):
+            return KernelOps.scores_given_gram(self, B, G, lam, n)
         p = B.shape[1]
         sd = self._solve(B.dtype)
         wd = B.dtype if sd is None else sd
@@ -861,8 +873,10 @@ class ShardedOps(KernelOps):
                       jitter: float) -> tuple[Array, Array, Array]:
         """Sharded §3.5 factor build: (scores, B, d_eff), collectives p×p.
 
-        W = k(Z, Z) and its jittered Cholesky are built once (replicated,
-        p×p); per shard C_blk = k(X_blk, Z) through the inner executor and
+        W = k(Z, Z) and its jittered Cholesky are built on every device
+        (replicated, p×p — inside the ``shard_map`` like every other inner
+        block, since a Mosaic kernel cannot be partitioned automatically);
+        per shard C_blk = k(X_blk, Z) through the inner executor and
         B_blk = C_blk L⁻ᵀ; one psum of B_blkᵀB_blk gives the global Gram
         for eq. (9) plus the scalar d_eff psum. Padded tail rows are
         masked out of the Gram (k(0, z) ≠ 0) and sliced off the outputs —
@@ -883,15 +897,15 @@ class ShardedOps(KernelOps):
         inner, ax = self.inner(), self.axis_name
         (X,) = self._cast_data(X)
         (landmarks,) = self._cast_data(landmarks)
-        W = inner.cross(landmarks, landmarks)
-        sd = self._solve(W.dtype)
-        Lc = jittered_cholesky(W if sd is None else W.astype(sd),
-                               storage_floored_jitter(jitter, W.dtype))
-        acc = self._accum(W.dtype)
+        dt = landmarks.dtype
+        sd, acc = self._solve(dt), self._accum(dt)
         (Xp,) = self._shard_rows(X)
-        mask = (jnp.arange(Xp.shape[0]) < n).astype(W.dtype)
+        mask = (jnp.arange(Xp.shape[0]) < n).astype(dt)
 
         def local(xb, mb, z):
+            W = inner.cross(z, z)
+            Lc = jittered_cholesky(W if sd is None else W.astype(sd),
+                                   storage_floored_jitter(jitter, dt))
             Cb = inner.cross(xb, z)
             # B rows come back in the block dtype (the factor is O(n·p)
             # state) even when the triangular solve ran at solve precision
@@ -928,17 +942,26 @@ class ShardedOps(KernelOps):
 
 # -------------------------------------------------------------- resolution
 
-def resolve_backend(name: str = "auto") -> str:
-    """Registry name for ``name``, resolving ``"auto"`` per platform.
+def resolve_backend(name: str = "auto", dtype=None) -> str:
+    """Registry name for ``name``, resolving ``"auto"`` per platform and
+    data dtype.
 
-    ``auto`` → ``pallas`` on TPU (the tiles lower to real mosaic kernels
-    there), ``xla`` everywhere else (on CPU/GPU the Pallas tiles would run
-    in interpret mode, which only exists for validation). Re-evaluated on
-    every call — keyed on the *current* ``jax.default_backend()`` — so
-    platform simulation in tests is never pinned by a first-call cache.
+    ``auto`` → ``pallas`` on TPU (the tiles lower to real Mosaic kernels
+    there) for blocks of any dtype the tiles take — f32 and bf16 — and
+    ``xla`` for float64 blocks on TPU, because Mosaic has no float64;
+    ``xla`` on every other platform (on CPU/GPU the Pallas tiles would run
+    in interpret mode, which only exists for validation). ``dtype`` is the
+    block (data) dtype when the caller knows it; ``None`` means "not known
+    yet", and an ``auto``-built ``pallas`` executor then applies the same
+    rule per block (``PallasOps.auto``). Re-evaluated on every call —
+    keyed on the *current* ``jax.default_backend()`` — so platform
+    simulation in tests is never pinned by a first-call cache.
     """
     if name == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        if jax.default_backend() != "tpu":
+            return "xla"
+        f64 = dtype is not None and jnp.dtype(dtype) == jnp.float64
+        return "xla" if f64 else "pallas"
     if name not in BACKENDS:
         BACKENDS.get(name)  # raises KeyError listing the available names
     return name
@@ -956,9 +979,11 @@ def ops_for(kernel: Kernel, backend: str = "auto",
     them inertly. ``precision`` is the per-stage dtype policy
     (``core.precision.Precision``; the default changes nothing).
     """
-    return BACKENDS.get(resolve_backend(backend))(
+    name = resolve_backend(backend)
+    extra = {"auto": True} if name == "pallas" and backend == "auto" else {}
+    return BACKENDS.get(name)(
         kernel=kernel, block_rows=block_rows, mesh_shape=mesh_shape,
-        inner_backend=inner_backend, precision=precision)
+        inner_backend=inner_backend, precision=precision, **extra)
 
 
 def ops_for_config(config) -> KernelOps:

@@ -5,18 +5,21 @@ real mosaic custom-call, so every wrapper auto-enables ``interpret=True``
 unless a TPU backend is present. On TPU the same call sites compile to the
 real kernels. ``use_pallas=False`` (e.g. inside the 512-device dry-run,
 where interpret mode under SPMD would be meaningless) routes to the jnp
-reference, which XLA fuses well — the kernels exist to beat that fusion on
-real hardware, and are validated against ``ref`` in tests.
+reference, which XLA fuses well. Whether the kernels beat that fusion on
+a TPU has not been measured yet; they are validated against ``ref`` in
+tests and compiled for a described TPU v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 from jax import Array
 
 from . import ref
 from .flash_attention import flash_attention as _flash
 from .rbf_block import kernel_block as _kernel_block
 from .rls_scores import rls_scores_fused as _rls_fused
+from .sparse_block import onehot_tile_fits
 from .sparse_block import sparse_kernel_block as _sparse_kernel_block
 
 
@@ -64,13 +67,26 @@ def sparse_block(data: Array, indices: Array, indptr: Array, Z: Array, *,
                  use_pallas: bool = True,
                  acc_dtype: str | None = None) -> Array:
     """CSR kernel block k(X_csr, Z) — the one sparse primitive behind
-    every backend's CSR path. On TPU with ``use_pallas`` this compiles
-    the one-hot MXU tiles; elsewhere it routes to the XLA take +
-    segment-sum reference rather than interpreting the Pallas body (the
-    one-hot matmuls only pay off on real MXU hardware — interpreting
-    them on CPU would be strictly slower than the fused XLA scan, which
-    is itself densification-free)."""
-    pallas = use_pallas and not _needs_interpret()
+    every backend's CSR path.
+
+    The contraction is picked by a written rule, never by catching a
+    lowering error:
+
+    * on a TPU, with ``use_pallas``, when the one-hot MXU tile fits its
+      VMEM budget at this block's widths (``onehot_tile_fits``: nnz
+      tile, row count, p and feature width d) → the Pallas one-hot tiles;
+    * otherwise → the XLA take + segment-sum scan. That covers every
+      non-TPU platform (interpreting the one-hot matmuls on CPU would be
+      strictly slower than the fused XLA scan, which is itself
+      densification-free) and TPU blocks too wide for the one-hots, such
+      as hashed features at d in the 10⁵–10⁶ or 4096-row chunks.
+    """
+    p, d = Z.shape
+    acc = jnp.dtype(acc_dtype) if acc_dtype else jnp.result_type(
+        data.dtype, Z.dtype)
+    pallas = (use_pallas and not _needs_interpret()
+              and onehot_tile_fits(data.shape[0], indptr.shape[0] - 1, p, d,
+                                   acc.itemsize))
     return _sparse_kernel_block(data, indices, indptr, Z, kind=kind,
                                 bandwidth=bandwidth, degree=degree,
                                 scale=scale, offset=offset,

@@ -16,8 +16,9 @@ Supported kernels: rbf (default), linear (skips the exp/sq-dist fusion),
 poly ((x·z/scale + offset)^degree, fused on the VPU).
 
 Accumulation dtype follows the input: float64 inputs accumulate in float64
-(interpret-mode/CPU validation, where the backend parity suite demands
-1e-10 agreement with the dense reference); everything narrower — f32 and
+(interpret-mode/CPU validation only, where the backend parity suite demands
+1e-10 agreement with the dense reference — Mosaic has no f64, and the
+``pallas`` executor never sends f64 blocks to a TPU); everything narrower — f32 and
 bf16 alike — accumulates in float32 as the MXU does, and the output tile is
 written back in the input dtype (bf16 in ⇒ bf16 blocks, f32 arithmetic).
 ``acc_dtype`` overrides that rule explicitly when a precision policy wants
@@ -29,12 +30,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import Array
 from jax.experimental import pallas as pl
 
 
 DEFAULT_BN = 256   # X rows per tile   (8-sublane aligned)
 DEFAULT_BP = 128   # landmarks per tile (128-lane aligned)
+
+# Block indices must be int32 for Mosaic: a bare Python ``0`` in an index
+# map is traced as int64 when ``jax_enable_x64`` is on, and Mosaic then
+# fails to legalize the index function's return.
+_ZERO = np.int32(0)
 
 
 def _acc_dtype(dtype) -> jnp.dtype:
@@ -115,8 +122,8 @@ def kernel_block(X: Array, Z: Array, *, bandwidth: float = 1.0,
         body,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bn_, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bp_, d), lambda i, j: (j, 0)),
+            pl.BlockSpec((bn_, d), lambda i, j: (i, _ZERO)),
+            pl.BlockSpec((bp_, d), lambda i, j: (j, _ZERO)),
         ],
         out_specs=pl.BlockSpec((bn_, bp_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Xp.shape[0], Zp.shape[0]), X.dtype),

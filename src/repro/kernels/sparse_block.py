@@ -20,8 +20,9 @@ so the whole block stays within nnz + O(n_rows·p) — the bound
 
 A Pallas TPU variant expresses the same tile as two MXU one-hot
 matmuls (column gather, row scatter) with ``@pl.when``-guarded output
-initialization. Off-TPU call sites use the XLA reference — the one-hot
-tiles only pay off on real MXU hardware (see ``kernels.ops``).
+initialization. It runs only on a TPU and only where its VMEM estimate
+fits (``onehot_tile_fits``); every other call site uses the XLA
+reference (see ``kernels.ops.sparse_block``).
 
 Zero-valued structural padding is harmless by construction: padded nnz
 slots carry ``data == 0`` and padded tail rows get row id ``n_rows``,
@@ -36,11 +37,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import Array
 from jax.experimental import pallas as pl
 
 __all__ = [
-    "MIN_TILE", "sparse_tile", "sparse_cell_bound", "sparse_row_ids",
+    "MIN_TILE", "ONEHOT_VMEM_BUDGET", "onehot_tile_fits", "sparse_tile",
+    "sparse_cell_bound", "sparse_row_ids",
     "sparse_row_sqnorms", "sparse_cross", "sparse_kernel_block",
 ]
 
@@ -50,6 +53,13 @@ MIN_TILE = 512
 
 # Pallas lane width — the TPU tile's minor dimension granularity
 _LANE = 128
+
+# VMEM the one-hot tile may plan for: half of the 16 MiB a Mosaic kernel
+# may use by default on TPU v5e, leaving the compiler room for its own
+# temporaries (``onehot_tile_fits``)
+ONEHOT_VMEM_BUDGET = 8 * 2**20
+
+_ZERO = np.int32(0)   # int32 block index (x64-safe, see rbf_block)
 
 
 def sparse_tile(nnz_cap: int, n_rows: int) -> int:
@@ -121,29 +131,48 @@ def _pallas_tile_body(d_ref, c_ref, r_ref, z_ref, o_ref, *, acc,
                       n_rows: int, n_cols: int):
     """One nnz tile as two MXU passes: a one-hot column matmul gathers
     landmark columns, a one-hot row matmul scatter-adds into the output
-    block. Output is zeroed on the first tile and accumulated after."""
+    block. Output is zeroed on the first tile and accumulated after.
+
+    The nnz stream stays on the lane axis throughout — the one-hots are
+    built transposed, (d, tile) and (n_rows, tile), so no vector is ever
+    relaid from lanes to sublanes."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    dat = d_ref[0, :].astype(acc)                            # (tile,)
-    col = c_ref[0, :]
-    row = r_ref[0, :]
-    tile = dat.shape[0]
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, n_cols), 1)
-    onehot_c = (col[:, None] == col_iota).astype(acc)        # (tile, d)
-    g = jax.lax.dot_general(onehot_c, z_ref[...].astype(acc),
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=acc)       # (tile, p)
-    g = g * dat[:, None]
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, n_rows), 1)
-    onehot_r = (row[:, None] == row_iota).astype(acc)        # (tile, n)
+    dat = d_ref[...].astype(acc)                              # (1, tile)
+    tile = dat.shape[1]
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (n_cols, tile), 0)
+    onehot_c = (col_iota == c_ref[...]).astype(acc)           # (d, tile)
+    g = jax.lax.dot_general(z_ref[...].astype(acc), onehot_c,
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=acc) * dat  # (p, tile)
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (n_rows, tile), 0)
+    onehot_r = (row_iota == r_ref[...]).astype(acc)           # (n, tile)
     o_ref[...] += jax.lax.dot_general(onehot_r, g,
-                                      (((0,), (0,)), ((), ())),
+                                      (((1,), (1,)), ((), ())),
                                       preferred_element_type=acc
                                       ).astype(o_ref.dtype)
+
+
+def onehot_tile_fits(nnz_cap: int, n_rows: int, p: int, d: int,
+                     itemsize: int = 4) -> bool:
+    """Whether the one-hot MXU tile fits ``ONEHOT_VMEM_BUDGET`` for a CSR
+    block of ``nnz_cap`` slots over ``n_rows`` rows against (p, d)
+    landmarks — the rule ``kernels.ops.sparse_block`` selects the tile
+    by on a TPU. The estimate counts the double-buffered (p, d) landmark
+    block and (n_rows, p) output block, the three double-buffered
+    (1, tile) nnz streams (each padded to 8 sublanes), and the live
+    one-hots, their iotas and the (p, tile) gather. Both one-hots grow
+    with the feature width d and the block's row count, so hashed
+    feature widths (d in the 10⁵–10⁶) take the XLA take + segment-sum
+    contraction instead."""
+    tile = -(-sparse_tile(nnz_cap, n_rows) // _LANE) * _LANE
+    vmem = itemsize * (2 * p * d + 2 * n_rows * p + 3 * 2 * 8 * tile
+                       + 2 * (d + n_rows) * tile + p * tile + n_rows * p)
+    return vmem <= ONEHOT_VMEM_BUDGET
 
 
 def _sparse_cross_pallas(data: Array, indices: Array, rows: Array,
@@ -151,17 +180,19 @@ def _sparse_cross_pallas(data: Array, indices: Array, rows: Array,
                          interpret: bool) -> Array:
     steps = data.shape[0] // tile
     p, d = Z.shape
-    shaped = [a.reshape(steps, tile) for a in (data, indices, rows)]
+    # (steps, 1, tile): each program's nnz slice is a (1, tile) block
+    # spanning the array's two minor dims whole, as Mosaic's 8x128 rule
+    # allows; a (1, tile) block of a (steps, tile) array does not lower
+    shaped = [a.reshape(steps, 1, tile) for a in (data, indices, rows)]
     body = functools.partial(_pallas_tile_body, acc=acc, n_rows=n_rows,
                              n_cols=d)
+    stream = pl.BlockSpec((None, 1, tile), lambda i: (i, _ZERO, _ZERO))
     return pl.pallas_call(
         body,
         grid=(steps,),
-        in_specs=[pl.BlockSpec((1, tile), lambda i: (i, 0)),
-                  pl.BlockSpec((1, tile), lambda i: (i, 0)),
-                  pl.BlockSpec((1, tile), lambda i: (i, 0)),
-                  pl.BlockSpec((p, d), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((n_rows, p), lambda i: (0, 0)),
+        in_specs=[stream, stream, stream,
+                  pl.BlockSpec((p, d), lambda i: (_ZERO, _ZERO))],
+        out_specs=pl.BlockSpec((n_rows, p), lambda i: (_ZERO, _ZERO)),
         out_shape=jax.ShapeDtypeStruct((n_rows, p), acc),
         interpret=interpret,
     )(*shaped, Z)

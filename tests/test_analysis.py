@@ -70,9 +70,8 @@ class TestJaxprRules:
         assert found and "scan" in found[0].where
 
     def test_collective_bound_and_no_collectives(self):
-        from repro.core.backends import shard_map   # version-compat shim
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("i",))
-        psum = shard_map(lambda x: jax.lax.psum(x, "i"), mesh=mesh,
+        psum = jax.shard_map(lambda x: jax.lax.psum(x, "i"), mesh=mesh,
                          in_specs=jax.sharding.PartitionSpec("i"),
                          out_specs=jax.sharding.PartitionSpec())
         jx = jax.make_jaxpr(psum)(jnp.ones((8, 8)))

@@ -358,6 +358,32 @@ class TestResolution:
         monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
         assert resolve_backend("auto") == "xla"
 
+    def test_auto_resolution_follows_dtype_on_tpu(self, monkeypatch):
+        """Mosaic has no f64: on a TPU ``auto`` sends f64 blocks to XLA."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert resolve_backend("auto", jnp.float32) == "pallas"
+        assert resolve_backend("auto", jnp.bfloat16) == "pallas"
+        assert resolve_backend("auto", jnp.float64) == "xla"
+        assert resolve_backend("pallas", jnp.float64) == "pallas"
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert resolve_backend("auto", jnp.float32) == "xla"
+
+    def test_f64_blocks_on_tpu(self, monkeypatch):
+        """An ``auto``-built pallas executor computes f64 blocks with the
+        dense XLA formula on a TPU; an explicit ``pallas`` one raises,
+        naming the dtype and the policy field."""
+        X = jax.random.normal(jax.random.key(0), (40, 5), jnp.float64)
+        ker = KERNEL_INSTANCES["rbf"]
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        auto = ops_for(ker, "auto")
+        assert auto.name == "pallas" and auto.auto
+        np.testing.assert_array_equal(
+            np.asarray(auto.cross(X, X[:7])),
+            np.asarray(ops_for(ker, "xla").cross(X, X[:7])))
+        with pytest.raises(ValueError,
+                           match=r"float64.*Precision\.data_dtype"):
+            ops_for(ker, "pallas").cross(X, X[:7])
+
     def test_unknown_backend_fails_loudly(self):
         with pytest.raises(KeyError, match="streaming"):
             resolve_backend("bogus")

@@ -9,8 +9,6 @@ import subprocess
 import sys
 import textwrap
 
-from tests.test_models_smoke import lm_stack_xfail
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -59,7 +57,6 @@ class TestDistributedRLS:
 
 
 class TestShardingRules:
-    @lm_stack_xfail
     def test_param_specs_divisibility(self):
         code = textwrap.dedent("""
             import jax, json, numpy as np
@@ -102,7 +99,6 @@ class TestShardingRules:
         assert res["m8"] == {"data": 4, "model": 2}
         assert res["m6"] == {"data": 3, "model": 2}
 
-    @lm_stack_xfail
     def test_train_step_shards_and_runs(self):
         """End-to-end: jit train step with explicit shardings on 8 devices."""
         code = textwrap.dedent("""

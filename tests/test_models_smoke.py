@@ -15,20 +15,6 @@ from repro.models import (decode_step, forward, init_decode_state,
 from repro.optim import AdamWConfig
 from repro.runtime import init_train_state, make_train_step
 
-# The LM stack (models/, optim/, parts of runtime/) predates the KRR work
-# and fails on the container's jax 0.4.37 — tracked in ROADMAP "Open
-# items". strict=False so archs that DO pass (or a future jax bump fixing
-# the rest) report xpass rather than breaking the lane.
-lm_stack_xfail = pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing LM-stack failure on jax 0.4.37 (ROADMAP: Open "
-           "items — seed LM-stack tests)")
-
-# The decode-step smoke passes deterministically on the pinned jax 0.4.37
-# for every arch except the two MoE stacks, so the xfail blanket is scoped
-# down to just those (xpass audit).
-DECODE_STEP_FAILING = frozenset({"deepseek-moe-16b", "llama4-scout-17b-a16e"})
-
 
 def small_cfg(name: str, **kw):
     cfg = get_config(name)
@@ -70,7 +56,6 @@ def _batch(cfg, B=2, S=64, seed=0):
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)
 class TestArchSmoke:
-    @lm_stack_xfail
     def test_forward_shapes_no_nan(self, arch):
         cfg = small_cfg(arch)
         params = init_model(cfg, jax.random.key(0))
@@ -84,7 +69,6 @@ class TestArchSmoke:
             assert out.logits.shape == (2, 64, expect_v)
         assert not bool(jnp.isnan(out.logits).any())
 
-    @lm_stack_xfail
     def test_train_step_decreases_nothing_nan(self, arch):
         cfg = small_cfg(arch)
         params = init_model(cfg, jax.random.key(0))
@@ -99,9 +83,7 @@ class TestArchSmoke:
         # same batch twice: loss must drop
         assert float(out2.metrics["loss"]) < float(out.metrics["loss"])
 
-    def test_decode_step_advances(self, arch, request):
-        if arch in DECODE_STEP_FAILING:
-            request.node.add_marker(lm_stack_xfail)
+    def test_decode_step_advances(self, arch):
         cfg = small_cfg(arch)
         params = init_model(cfg, jax.random.key(0))
         st = init_decode_state(cfg, 2, 128)
@@ -119,7 +101,6 @@ class TestArchSmoke:
 class TestDecodeConsistency:
     """Decode step must reproduce teacher-forced forward logits."""
 
-    @lm_stack_xfail
     @pytest.mark.parametrize("arch", ["chatglm3-6b", "mamba2-780m",
                                       "gemma2-2b", "zamba2-7b"])
     def test_decode_matches_forward(self, arch):
@@ -140,7 +121,6 @@ class TestDecodeConsistency:
 
 
 class TestNystromConfigs:
-    @lm_stack_xfail
     def test_nystrom_attention_trains(self):
         cfg = small_cfg("phi4-mini-3.8b", attn_approx="nystrom_rls",
                         nystrom_landmarks=32)

@@ -7,7 +7,7 @@ import pytest
 
 from repro.optim import AdamWConfig, adamw_update, init_adamw, schedule
 from repro.runtime import Request, ServeEngine
-from tests.test_models_smoke import lm_stack_xfail, small_cfg
+from tests.test_models_smoke import small_cfg
 
 
 class TestAdamW:
@@ -124,7 +124,6 @@ class TestKRRServeQuantized:
 
 
 class TestSSMDecodeParity:
-    @lm_stack_xfail
     def test_chunked_vs_recurrent(self):
         """SSD chunked training forward == step-by-step recurrence."""
         cfg = small_cfg("mamba2-780m")
@@ -146,7 +145,6 @@ class TestSSMDecodeParity:
 
 
 class TestHloCostAnalyzer:
-    @lm_stack_xfail
     def test_scan_trip_multiplication(self):
         from repro.launch.hlo_cost import analyze_hlo
 
@@ -164,7 +162,6 @@ class TestHloCostAnalyzer:
         r = analyze_hlo(c.as_text())
         assert r.flops == pytest.approx(2 * 16 * 128 * 128 * 8)
 
-    @lm_stack_xfail
     def test_collective_bytes_counted(self):
         from repro.launch.hlo_cost import analyze_hlo
         from jax.sharding import PartitionSpec as P
