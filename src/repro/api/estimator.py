@@ -48,12 +48,14 @@ in-memory ones; only the closed-form diagnostics (``risk``,
 from __future__ import annotations
 
 import os
+from functools import partial
 from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import Array
+from jax.profiler import TraceAnnotation, annotate_function
 
 from ..core.backends import KernelOps, ops_for_config
 from ..core.krr import RiskReport, empirical_risk
@@ -142,6 +144,7 @@ class SketchedKRR:
             return jnp.asarray(arr)
         return jnp.asarray(arr, dtype=jnp.dtype(dt))
 
+    @partial(annotate_function, name="estimator.fit")
     def fit(self, X, y: Array | None = None) -> "SketchedKRR":
         """Fit from an in-memory array — or out-of-core from a chunk source.
 
@@ -209,7 +212,8 @@ class SketchedKRR:
         # pass at fit time; scores()/sample() run it lazily from the same
         # key, so diagnostics stay available and deterministic.
         sample = self._run_sampler() if self._solver.needs_sample else None
-        self._state = self._solver.fit(cfg, X, y, sample, key_solve)
+        with TraceAnnotation("solver.fit"):
+            self._state = self._solver.fit(cfg, X, y, sample, key_solve)
         self._predict_jit = None
         return self
 

@@ -40,6 +40,7 @@ from typing import NamedTuple, Protocol
 import jax
 import jax.numpy as jnp
 from jax import Array
+from jax.profiler import TraceAnnotation
 
 from ..core.backends import ops_for_config
 from ..core.bless import bless_leverage
@@ -72,8 +73,9 @@ SAMPLERS: Registry[Sampler] = Registry("sampler")
 
 
 def _finish(key: Array, scores: Array, p: int) -> SamplerOutput:
-    probs = scores / jnp.sum(scores)
-    return SamplerOutput(draw_columns(key, probs, p), scores)
+    with TraceAnnotation("sampler.draw"):
+        probs = scores / jnp.sum(scores)
+        return SamplerOutput(draw_columns(key, probs, p), scores)
 
 
 @SAMPLERS.register("uniform")
@@ -112,10 +114,11 @@ def rls_fast(key: Array, kernel: Kernel, X: Array,
     ``config.score_pass_p`` landmarks, then the Theorem-3 leverage draw
     of ``config.p`` columns — O(n·p_scores²)."""
     kd, ks = jax.random.split(key)
-    fast = fast_ridge_leverage(kernel, X, config.lam * config.eps,
-                               min(config.score_pass_p, X.shape[0]), kd,
-                               jitter=config.jitter,
-                               ops=ops_for_config(config))
+    with TraceAnnotation("sampler.score_pass"):
+        fast = fast_ridge_leverage(kernel, X, config.lam * config.eps,
+                                   min(config.score_pass_p, X.shape[0]), kd,
+                                   jitter=config.jitter,
+                                   ops=ops_for_config(config))
     return _finish(ks, fast.scores, config.p)
 
 

@@ -209,7 +209,9 @@ def _dense_score_pass(ops):
     traced arguments — a new λ never recompiles, only a new (n, p) shape
     does. This is what keeps a BLESS stage's cost at its FLOPs: eagerly,
     the ~15 dispatches here dwarf a small stage's whole score pass."""
-    return jax.jit(partial(_dense_pass_body, ops))
+    def score_pass(X, idx, lam, jitter):
+        return _dense_pass_body(ops, X, idx, lam, jitter)
+    return jax.jit(score_pass)
 
 
 @partial(jax.jit, static_argnums=(3,))

@@ -40,6 +40,7 @@ from concurrent.futures import Future
 from typing import Any, Mapping, NamedTuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .queue import (DeadlineMissError, EngineStoppedError, FifoQueue,
                     QueueFullError, ServeRequest, UnknownModelError)
@@ -211,6 +212,7 @@ class AsyncServeEngine:
         self._queue: FifoQueue[ServeRequest] = FifoQueue(
             clock, max_depth=policy.max_queue_depth)
         self._uid = itertools.count()
+        self._batch_no = itertools.count()
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._stats = ServeStats()
@@ -348,13 +350,18 @@ class AsyncServeEngine:
 
     def _serve_loop(self) -> None:
         while not self._stop.is_set():
-            batch = self._queue.next_batch(
-                self.policy.max_batch, self.policy.max_wait_ms / 1e3,
-                deadline_of=lambda r: r.deadline, stop=self._stop)
+            with TraceAnnotation("serve.wait"):
+                batch = self._queue.next_batch(
+                    self.policy.max_batch, self.policy.max_wait_ms / 1e3,
+                    deadline_of=lambda r: r.deadline, stop=self._stop)
             if batch:
-                self._serve_batch(batch)
+                with TraceAnnotation("serve.batch") as span:
+                    span.set_metadata(**self._serve_batch(batch))
 
-    def _serve_batch(self, batch: list[ServeRequest]) -> None:
+    def _serve_batch(self, batch: list[ServeRequest]) -> dict:
+        """Serve one popped batch; returns the metadata of its
+        ``serve.batch`` span: its running number, the live rows, the
+        padded rows run, and the queue wait of its requests at pop."""
         now = self._clock()
         live: list[ServeRequest] = []
         for req in batch:
@@ -375,28 +382,37 @@ class AsyncServeEngine:
         groups: dict[str, list[ServeRequest]] = {}
         for req in live:
             groups.setdefault(req.model, []).append(req)
+        padded = 0
         for key, reqs in groups.items():
             try:
-                self._serve_group(key, reqs)
+                padded += self._serve_group(key, reqs)
             except BaseException as exc:     # noqa: BLE001 — forwarded
                 for req in reqs:
                     if not req.future.done():
                         req.future.set_exception(exc)
+        waits = [now - req.submitted for req in batch]
+        return {"batch": next(self._batch_no), "rows": len(live),
+                "bucket": padded, "wait_max_us": max(waits) * 1e6,
+                "wait_mean_us": sum(waits) / len(waits) * 1e6}
 
-    def _serve_group(self, key: str, reqs: list[ServeRequest]) -> None:
+    def _serve_group(self, key: str, reqs: list[ServeRequest]) -> int:
+        """Serve one model's requests as one padded batch; returns the
+        bucket it ran at."""
         entry = self._slots[key].current()   # ONE snapshot for the batch
         bucket = self.policy.bucket_for(len(reqs), entry.n_shards)
-        y = entry.predict_padded(np.stack([r.x for r in reqs]), bucket)
+        y = entry.predict_padded([r.x for r in reqs], bucket)
         done = self._clock()
-        lats = []
-        for req, val in zip(reqs, y):
-            lat_ms = (done - req.submitted) * 1e3
-            lats.append(lat_ms)
-            req.future.set_result(ServeResult(
-                float(val), entry.key, entry.version, lat_ms))
-        with self._stats_lock:
-            self._stats.served += len(reqs)
-            self._stats.batches += 1
-            self._stats.batch_sizes.append(len(reqs))
-            self._stats.buckets.append(bucket)
-            self._stats.latencies_ms.extend(lats)
+        with TraceAnnotation("serve.respond"):
+            lats = []
+            for req, val in zip(reqs, y):
+                lat_ms = (done - req.submitted) * 1e3
+                lats.append(lat_ms)
+                req.future.set_result(ServeResult(
+                    float(val), entry.key, entry.version, lat_ms))
+            with self._stats_lock:
+                self._stats.served += len(reqs)
+                self._stats.batches += 1
+                self._stats.batch_sizes.append(len(reqs))
+                self._stats.buckets.append(bucket)
+                self._stats.latencies_ms.extend(lats)
+        return bucket

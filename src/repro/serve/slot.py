@@ -63,8 +63,9 @@ class PublishedModel:
     data_dtype: str | None
     predict_fn: Callable = dataclasses.field(repr=False, compare=False)
 
-    def predict_padded(self, X: np.ndarray, bucket: int) -> np.ndarray:
-        """Serve a ``(k, dim)`` host batch padded to ``bucket`` rows.
+    def predict_padded(self, X: Any, bucket: int) -> np.ndarray:
+        """Serve a ``(k, dim)`` host batch, or a sequence of ``k`` rows,
+        padded to ``bucket`` rows.
 
         Pads by repeating the last row (the same convention as
         ``SketchedKRR.predict_batched``) so the jitted predict sees one
@@ -78,26 +79,34 @@ class PublishedModel:
         per-bucket predict (eager jnp padding would JIT a fresh
         concatenate per distinct ``k`` — ~60 ms a pop on CPU, which
         dwarfs the predict itself).
+
+        Inside a ``jax.profiler`` session the host assembly, the upload
+        and the predict with its readback record as the spans
+        ``serve.pad``, ``serve.upload`` and ``serve.predict``.
         """
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
 
-        k = X.shape[0]
+        k = len(X)
         if k > bucket:
             raise ValueError(f"batch of {k} exceeds bucket {bucket}")
-        Xp = np.asarray(X)
-        pad = bucket - k
-        if pad:
-            Xp = np.concatenate(
-                [Xp, np.broadcast_to(Xp[-1:], (pad,) + Xp.shape[1:])])
-        if self.data_dtype is None:
-            Xb = jnp.asarray(Xp)
-        else:
-            Xb = jnp.asarray(Xp, dtype=jnp.dtype(self.data_dtype))
-        if self.state is not None:
-            y = self.predict_fn(self.state, Xb)
-        else:
-            y = self.predict_fn(Xb)
-        return np.asarray(y)[:k]
+        with TraceAnnotation("serve.pad"):
+            Xp = np.asarray(X)
+            pad = bucket - k
+            if pad:
+                Xp = np.concatenate(
+                    [Xp, np.broadcast_to(Xp[-1:], (pad,) + Xp.shape[1:])])
+        with TraceAnnotation("serve.upload"):
+            if self.data_dtype is None:
+                Xb = jnp.asarray(Xp)
+            else:
+                Xb = jnp.asarray(Xp, dtype=jnp.dtype(self.data_dtype))
+        with TraceAnnotation("serve.predict"):
+            if self.state is not None:
+                y = self.predict_fn(self.state, Xb)
+            else:
+                y = self.predict_fn(Xb)
+            return np.asarray(y)[:k]
 
 
 class ModelSlot:
@@ -157,12 +166,14 @@ class ModelSlot:
             solver = SOLVERS.get(cfg.solver)
             serve = cfg.precision.serve()
             if serve is None:
-                fn = lambda st, Xb: solver.predict(cfg, st, Xb)
+                def serve_predict(st, Xb):
+                    return solver.predict(cfg, st, Xb)
             else:
                 qcfg = cfg.replace(precision=cfg.precision.for_serving())
-                fn = lambda st, Xb: solver.predict(qcfg, st,
-                                                   Xb.astype(serve))
-            self._fn = jax.jit(fn)
+
+                def serve_predict(st, Xb):
+                    return solver.predict(qcfg, st, Xb.astype(serve))
+            self._fn = jax.jit(serve_predict)
             self._fn_cfg = cfg
         return self._fn
 
