@@ -26,10 +26,12 @@ one-pass O(p²) statistics — no O(n·p) state, any n.
 from __future__ import annotations
 
 import math
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import Array
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
@@ -240,88 +242,147 @@ class LandmarkPCG(NamedTuple):
     residuals: Array   # (iters,) relative residual ‖r‖/‖b‖ per iteration
 
 
-def pcg_solve(matvec, b: Array, msolve=None, *, tol: float = 1e-6,
-              max_iters: int = 100) -> tuple[Array, int, Array]:
+def _coldot(u: Array, v: Array) -> Array:
+    return jnp.sum(u * v, axis=0)
+
+
+def _unpreconditioned(operands, r: Array) -> Array:
+    return r
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def pcg_step(matvec, msolve, operands, bfloor: Array, x: Array, r: Array,
+             pvec: Array, rz: Array) -> tuple:
+    """One PCG iteration; returns ``(x, r, pvec, rz, rel)``.
+
+    ``matvec`` and ``msolve`` are static: module-level functions, or ones
+    cached per executor (:func:`sketch_normal_matvec`), so equal fits pass
+    the very same objects. Every array they read — and every scalar that
+    varies between fits, such as nλ — arrives in ``operands``, so the
+    lowered step holds no array constant and compiles once per (operator,
+    preconditioner, shapes): a second fit, a new λ or new rows reuse it,
+    and the persistent compile cache can hold it.
+    """
+    tiny = float(jnp.finfo(x.dtype).tiny)
+    Hp = matvec(operands, pvec)
+    a = rz / jnp.maximum(_coldot(pvec, Hp), tiny)
+    x = x + a * pvec
+    r = r - a * Hp
+    z = msolve(operands, r)
+    rz_new = _coldot(r, z)
+    bs = rz_new / jnp.maximum(rz, tiny)
+    pvec = z + bs * pvec
+    rel = jnp.max(jnp.sqrt(_coldot(r, r)) / bfloor)
+    return x, r, pvec, rz_new, rel
+
+
+def pcg_solve(matvec, b: Array, msolve=None, operands=None, *,
+              tol: float = 1e-6, max_iters: int = 100
+              ) -> tuple[Array, int, Array]:
     """Preconditioned conjugate gradients on an SPD operator.
 
-    Generic engine behind both FALKON routes: ``matvec`` is any linear map
-    v ↦ Hv (implicit backend-streamed kernel passes, accumulated p×p
-    statistics, …) and ``msolve`` an optional preconditioner application
-    r ↦ M⁻¹r (``None`` = unpreconditioned CG — kept callable so benchmarks
-    can record both in the same run). Multi-output RHS columns of shape
-    (p, k) share each matvec, with per-column step sizes. One jitted CG
-    step re-used across the host-side iteration loop; stops when
-    max-over-columns ‖r‖/‖b‖ ≤ ``tol``. Denominators are floored at the
-    dtype tiny so a converged (or zero) system never divides by 0.
+    Generic engine behind both FALKON routes: ``matvec(operands, v)`` is
+    any linear map v ↦ Hv (implicit backend-streamed kernel passes,
+    accumulated p×p statistics, …) and ``msolve(operands, r)`` an optional
+    preconditioner application r ↦ M⁻¹r (``None`` = unpreconditioned CG —
+    kept callable so benchmarks can record both in the same run). Both are
+    pure functions of the ``operands`` pytree and must be hashable and
+    equal across calls (see :func:`pcg_step`). Multi-output RHS columns of
+    shape (p, k) share each matvec, with per-column step sizes. The jitted
+    :func:`pcg_step` runs once per iteration of a host-side loop that stops
+    when max-over-columns ‖r‖/‖b‖ ≤ ``tol``. Denominators are floored at
+    the dtype tiny so a converged (or zero) system never divides by 0.
 
     Returns ``(x, iters, residual_history)``.
     """
-    if msolve is None:
-        def msolve(r):
-            return r
-
-    def coldot(u, v):
-        return jnp.sum(u * v, axis=0)
-
+    msolve = msolve or _unpreconditioned
     tiny = float(jnp.finfo(b.dtype).tiny)
-    bfloor = jnp.maximum(jnp.sqrt(coldot(b, b)), tiny)
-
-    @jax.jit
-    def step(x, r, pvec, rz):
-        Hp = matvec(pvec)
-        a = rz / jnp.maximum(coldot(pvec, Hp), tiny)
-        x = x + a * pvec
-        r = r - a * Hp
-        z = msolve(r)
-        rz_new = coldot(r, z)
-        bs = rz_new / jnp.maximum(rz, tiny)
-        pvec = z + bs * pvec
-        rel = jnp.max(jnp.sqrt(coldot(r, r)) / bfloor)
-        return x, r, pvec, rz_new, rel
-
+    bfloor = jnp.maximum(jnp.sqrt(_coldot(b, b)), tiny)
     x = jnp.zeros_like(b)
     r = b
-    pvec = msolve(r)
-    rz = coldot(r, pvec)
+    pvec = msolve(operands, r)
+    rz = _coldot(r, pvec)
     # trace-time (auditor) fallback inf: no early stop, so the traced
     # solve unrolls the full ``max_iters`` — the worst case of any eager
     # run, which is exactly what the space-invariant audit must bound
-    rel = concrete_float(jnp.max(jnp.sqrt(coldot(r, r)) / bfloor),
+    rel = concrete_float(jnp.max(jnp.sqrt(_coldot(r, r)) / bfloor),
                          math.inf)
     history = []
     it = 0
     while it < max_iters and rel > tol:
-        # the first step's span holds its compile, the rest one device
-        # iteration each (the residual read waits for it)
+        # the first step at new shapes holds its compile (or its load from
+        # the persistent cache), the rest one device iteration each (the
+        # residual read waits for it)
         with jax.profiler.TraceAnnotation("solver.pcg_step", it=it):
-            x, r, pvec, rz, rel_j = step(x, r, pvec, rz)
+            x, r, pvec, rz, rel_j = pcg_step(matvec, msolve, operands,
+                                             bfloor, x, r, pvec, rz)
             rel = concrete_float(rel_j, math.inf)
         it += 1
         history.append(rel)
-    return x, it, jnp.asarray(history, dtype=jnp.float32)
+    # cast on the host: a device cast would compile once per history length
+    return x, it, jnp.asarray(np.asarray(history, dtype=np.float32))
 
 
-def nystrom_pcg_preconditioner(W: Array, weights: Array, n: int, lam: float,
-                               gamma: float, jitter: float):
-    """r ↦ M⁻¹r for M = Ws·Ws + nλ·A — the FALKON preconditioner.
+@lru_cache(maxsize=32)
+def sketch_normal_matvec(ops: KernelOps):
+    """v ↦ w ∘ gram_matvec(X, Z, w ∘ v) + nλ·Av over the operands ``X``,
+    ``Z``, ``w``, ``A`` and ``nlam`` — one function per executor value
+    (``KernelOps`` hash by configuration), so every fit through equal
+    executors shares one compiled :func:`pcg_step`."""
+    def matvec(operands, v: Array) -> Array:
+        A, w = operands["A"], operands["w"]
+        kv = ops.gram_matvec(operands["X"], operands["Z"], w * v)
+        return w * kv.astype(A.dtype) + operands["nlam"] * (A @ v)
+    return matvec
+
+
+def stats_normal_matvec(operands, v: Array) -> Array:
+    """v ↦ Gs·v + nλ·Av over the operands ``Gs``, ``A`` and ``nlam``."""
+    return operands["Gs"] @ v + operands["nlam"] * (operands["A"] @ v)
+
+
+def cholesky_msolve(operands, r: Array) -> Array:
+    """r ↦ (L Lᵀ)⁻¹r: two p×p triangular solves against operand ``L``."""
+    L = operands["L"]
+    z = jax.scipy.linalg.solve_triangular(L, r, lower=True)
+    return jax.scipy.linalg.solve_triangular(L, z, trans=1, lower=True)
+
+
+def nystrom_pcg_preconditioner(W: Array, weights: Array, A: Array,
+                               nlam: float, jitter: float) -> Array:
+    """L with L Lᵀ = M = Ws·Ws + nλ·A — the FALKON preconditioner's factor,
+    applied by :func:`cholesky_msolve`.
 
     With sketch weights w_j² = 1/(p·q_j) (``draw_columns``), Ws² is the
     importance-corrected unbiased estimate of CsᵀCs under ANY sampling
     distribution (uniform reduces it to the classic (n/p)²W² FALKON
     matrix), so M ≈ H = CsᵀCs + nλA and the PCG spectrum clusters at 1.
-    M is SPD (A ⪰ nγI) and factored once by the shared jittered Cholesky;
-    application is two p×p triangular solves per iteration.
+    M is SPD (A ⪰ nγI, the caller's ``regularized_penalty``) and factored
+    once by the shared jittered Cholesky; application is two p×p
+    triangular solves per iteration.
     """
     Ws = (W * weights[None, :]) * weights[:, None]
+    M = Ws @ Ws + nlam * A
+    return jittered_cholesky(M, jitter)
+
+
+def _landmark_pcg(matvec, operands: dict, b: Array, W: Array,
+                  weights: Array, n: int, lam: float, gamma: float,
+                  jitter: float, precondition: bool, tol: float,
+                  max_iters: int) -> LandmarkPCG:
+    """The two FALKON routes' shared tail: the penalty A, nλ and the
+    preconditioner's factor join ``operands``, then :func:`pcg_solve`."""
     A = regularized_penalty(W, weights, n, gamma)
-    M = Ws @ Ws + (n * lam) * A
-    L = jittered_cholesky(M, jitter)
-
-    def msolve(r):
-        z = jax.scipy.linalg.solve_triangular(L, r, lower=True)
-        return jax.scipy.linalg.solve_triangular(L.T, z, lower=False)
-
-    return msolve
+    nlam = n * lam
+    operands = dict(operands, A=A, nlam=jnp.asarray(nlam, A.dtype))
+    msolve = None
+    if precondition:
+        operands["L"] = nystrom_pcg_preconditioner(W, weights, A, nlam,
+                                                   jitter)
+        msolve = cholesky_msolve
+    beta, iters, res = pcg_solve(matvec, b, msolve, operands, tol=tol,
+                                 max_iters=max_iters)
+    return LandmarkPCG(beta, iters, res)
 
 
 def falkon_pcg_krr(ops: KernelOps, X: Array, y: Array, Z: Array,
@@ -348,23 +409,13 @@ def falkon_pcg_krr(ops: KernelOps, X: Array, y: Array, Z: Array,
     _, sd = landmark_solve_dtypes(ops, Z.dtype)
     W = ops.cross(Z, Z).astype(sd)
     wgt = weights.astype(sd)
-    A = regularized_penalty(W, wgt, n, gamma)
-    nlam = n * lam
     ry = ops.rmatvec(X, Z, y)
     wcol = wgt.reshape((-1,) + (1,) * (ry.ndim - 1))
     b = wcol * ry.astype(sd)
-
-    def matvec(v):
-        kv = ops.gram_matvec(X, Z, wcol * v)
-        return wcol * kv.astype(sd) + nlam * (A @ v)
-
-    msolve = None
-    if precondition:
-        msolve = nystrom_pcg_preconditioner(
-            W, wgt, n, lam, gamma, storage_floored_jitter(jitter, Z.dtype))
-    beta, iters, res = pcg_solve(matvec, b, msolve, tol=tol,
-                                 max_iters=max_iters)
-    return LandmarkPCG(beta, iters, res)
+    return _landmark_pcg(sketch_normal_matvec(ops), dict(X=X, Z=Z, w=wcol),
+                         b, W, wgt, n, lam, gamma,
+                         storage_floored_jitter(jitter, Z.dtype),
+                         precondition, tol, max_iters)
 
 
 def falkon_pcg_from_stats(W: Array, weights: Array, Gc: Array, bc: Array,
@@ -382,17 +433,6 @@ def falkon_pcg_from_stats(W: Array, weights: Array, Gc: Array, bc: Array,
     re-streaming rows per CG iteration. All inputs are expected in the
     caller's solve dtype.
     """
-    A = regularized_penalty(W, weights, n, gamma)
-    nlam = n * lam
-    Gs = 0.5 * (Gc + Gc.T)
-
-    def matvec(v):
-        return Gs @ v + nlam * (A @ v)
-
-    msolve = None
-    if precondition:
-        msolve = nystrom_pcg_preconditioner(W, weights, n, lam, gamma,
-                                            jitter)
-    beta, iters, res = pcg_solve(matvec, bc, msolve, tol=tol,
-                                 max_iters=max_iters)
-    return LandmarkPCG(beta, iters, res)
+    return _landmark_pcg(stats_normal_matvec, dict(Gs=0.5 * (Gc + Gc.T)),
+                         bc, W, weights, n, lam, gamma, jitter,
+                         precondition, tol, max_iters)

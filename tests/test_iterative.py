@@ -6,20 +6,26 @@ across the xla / streaming / sharded executors, in memory and through
 ``fit(ChunkSource)`` with multi-epoch streaming; falkon's Nyström
 preconditioner reaches 1e-3 within 50 iterations and beats plain CG in
 the same run; the jaxpr of every per-step computation holds no
-intermediate of size ≥ n·p.
+intermediate of size ≥ n·p; the PCG step takes its arrays as arguments,
+so it lowers to a small module and a refit compiles nothing.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.analysis import (MaxIntermediate, assert_audit,
+from repro.analysis import (CompileCounter, MaxIntermediate, assert_audit,
                             max_intermediate_size)
 from repro.api import (ArrayChunkSource, GeneratorChunkSource, SketchConfig,
                        SketchedKRR)
 from repro.api.solvers import SOLVERS, IterativeState
 from repro.core import RBFKernel, ops_for
-from repro.core.distributed import falkon_pcg_krr
+import repro.core.distributed as distributed
+from repro.core.distributed import (cholesky_msolve, falkon_pcg_krr,
+                                    pcg_step, sketch_normal_matvec,
+                                    stats_normal_matvec)
 from repro.core.eigenpro import (auto_batch_rows, landmark_solve_dtypes,
                                  make_chunk_grad, make_chunk_step,
                                  sgd_epoch_budget, step_size)
@@ -247,6 +253,85 @@ class TestStepMemory:
         assert float(step_size(pre, 1)) == pytest.approx(0.99 / 5.0)
         assert float(step_size(pre, 10**9)) == pytest.approx(0.99 / 0.01,
                                                              rel=1e-3)
+
+
+def _largest_constant_bytes(module_text: str) -> int:
+    """Bytes of the largest array constant in a StableHLO module's text."""
+    biggest = 0
+    for dims, bits in re.findall(
+            r"constant dense<.*?> : tensor<((?:\d+x)*)[a-z]+(\d+)>",
+            module_text):
+        elems = 1
+        for dim in dims.split("x")[:-1]:
+            elems *= int(dim)
+        biggest = max(biggest, elems * -(-int(bits) // 8))
+    return biggest
+
+
+class TestStepProgram:
+    """The jitted PCG step reads every array through its arguments: its
+    module stays small at any n and p, and a second fit at the same
+    shapes — other rows, another λ — reuses the compiled step."""
+
+    # the benchmark's FALKON SUSY cell: n = 2^17 rows of width 18, p = 10^4
+    N_CELL, D_CELL, P_CELL = 2**17, 18, 10**4
+
+    @pytest.mark.parametrize("route", ["sketch-xla", "sketch-streaming",
+                                       "stats"])
+    def test_step_lowers_without_constants(self, route):
+        """Lowered from shapes alone (no 400 MB p×p array is made): under
+        1 MB of text and no array constant above 1 KB."""
+        n, d, p = self.N_CELL, self.D_CELL, self.P_CELL
+
+        def spec(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+        if route == "stats":
+            matvec, operands = stats_normal_matvec, dict(Gs=spec(p, p))
+        else:
+            ops = ops_for(RBFKernel(4.0), route.split("-")[1])
+            matvec = sketch_normal_matvec(ops)
+            operands = dict(X=spec(n, d), Z=spec(p, d), w=spec(p))
+        operands.update(A=spec(p, p), nlam=spec(), L=spec(p, p))
+        v = spec(p)
+        text = pcg_step.lower(matvec, cholesky_msolve, operands, spec(),
+                              v, v, v, spec()).as_text()
+        assert len(text) < 2**20
+        assert _largest_constant_bytes(text) <= 1024
+
+    def test_constant_reader_sees_a_captured_array(self):
+        """The reader above finds an array a closure captures."""
+        big = jnp.arange(4096.0, dtype=jnp.float32)
+        text = jax.jit(lambda v: v + big).lower(
+            jax.ShapeDtypeStruct((4096,), jnp.float32)).as_text()
+        assert _largest_constant_bytes(text) == 4096 * 4
+
+    @pytest.mark.parametrize("route", ["in_memory", "partial_fit"])
+    def test_refit_compiles_nothing_in_pcg_solve(self, route, monkeypatch):
+        if not CompileCounter.supported():
+            pytest.skip("this jax emits no compile event")
+        counts = []
+        solve = distributed.pcg_solve
+
+        def counted(*args, **kw):
+            with CompileCounter() as cc:
+                out = solve(*args, **kw)
+            counts.append(cc.count)
+            return out
+
+        monkeypatch.setattr(distributed, "pcg_solve", counted)
+        for seed, lam in [(0, 1e-3), (5, 3e-3)]:
+            X, y = _problem(seed=seed)
+            m = SketchedKRR(_cfg(solver="falkon_pcg", lam=lam))
+            if route == "in_memory":
+                m.fit(X, y)
+            else:
+                m.partial_fit(X[:150], y[:150])
+                m.partial_fit(X[150:], y[150:])
+                m.finalize()
+            assert m.state().iters >= 1
+        assert len(counts) == 2
+        assert counts[1] == 0
 
 
 class TestRegistry:

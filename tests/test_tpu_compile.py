@@ -19,6 +19,8 @@ import numpy as np
 import pytest
 
 from repro.core import LinearKernel, PolynomialKernel, RBFKernel, ops_for
+from repro.core.distributed import (cholesky_msolve, pcg_step,
+                                    sketch_normal_matvec)
 from repro.data.sparse import CsrMatrix, SparseChunkSource
 from repro.kernels import ops as kops
 from repro.kernels.rbf_block import kernel_block
@@ -139,3 +141,23 @@ def test_explicit_pallas_refuses_f64_on_tpu(chip, on_tpu):
     with pytest.raises(ValueError, match=r"float64.*Precision\.data_dtype"):
         jax.jit(ops.cross).lower(chip((N_ROWS, D), jnp.float64),
                                  chip((P, D), jnp.float64))
+
+
+def test_falkon_pcg_step(chip, on_tpu):
+    """FALKON's PCG step at the SUSY cell's shapes (n = 2^17 rows of width
+    18, p = 10^4 landmarks, f32) on the tiles: the kernel block runs in
+    Mosaic, every array is an argument of the executable (the p×p penalty
+    and factor, X and the landmarks) rather than a constant inside it, and
+    arguments plus scratch fit one chip's 16 GB."""
+    n, d, p = 2**17, 18, 10**4
+    ops = ops_for(RBFKernel(4.0), "pallas")
+    operands = dict(X=chip((n, d)), Z=chip((p, d)), w=chip((p,)),
+                    A=chip((p, p)), nlam=chip(()), L=chip((p, p)))
+    v = chip((p,))
+    compiled = pcg_step.lower(sketch_normal_matvec(ops), cholesky_msolve,
+                              operands, chip(()), v, v, v,
+                              chip(())).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert 2 * p * p * 4 <= mem.argument_size_in_bytes < 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
