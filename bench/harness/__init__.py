@@ -1,2 +1,2 @@
-"""The benchmark's harness: cells found by name, seeded data, the two
-drivers, the plain reference and the trace reduction."""
+"""The benchmark's harness: cells found by name, seeds, what the drivers
+share, the RBF configurations' plain reference and the trace reduction."""

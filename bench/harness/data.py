@@ -1,16 +1,9 @@
-"""Seeded rows for a configuration, made on the device in one jitted call.
-
-The rows stand in for a public tabular data set of the configuration's
-width ``d``: standard normal features and a smooth noisy target, the
-``target`` the configuration file lists under ``assumed``.
+"""Seeds: a run's ``--seed`` mixed into the 31-bit range that
+``jax.random.key`` and ``SketchConfig.seed`` take. A configuration's rows
+are made by the module its ``rows`` key names, ``bench/rows/<rows>.py``.
 """
 from __future__ import annotations
 
-import math
-from functools import partial
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -21,18 +14,8 @@ def seed31(seed: int) -> int:
     return int(np.random.SeedSequence(int(seed)).generate_state(1)[0] >> 1)
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3))
-def _rows(key, n: int, m: int, d: int):
-    kx, kw, ke = jax.random.split(key, 3)
-    X = jax.random.normal(kx, (n + m, d), jnp.float32)
-    w = jax.random.normal(kw, (d,), jnp.float32) / math.sqrt(d)
-    y = (jnp.tanh(X @ w) + 0.5 * jnp.sin(X[:, 0])
-         + 0.1 * jax.random.normal(ke, (n + m,), jnp.float32))
-    return X[:n], y[:n], X[n:], y[n:]
-
-
-def make_data(config: dict, seed: int):
-    """``(X, y, Xt, yt)``: ``n_train`` training rows and ``n_test``
-    held-out rows of width ``d``, float32, on the default device."""
-    return _rows(jax.random.key(seed31(seed)), config["n_train"],
-                 config["n_test"], config["d"])
+def fit_seed(seed: int, i: int) -> int:
+    """The sketch seed of a run's ``i``-th fit: ``--seed`` and ``i`` mixed
+    into 31 bits, so each fit of a run draws its own sketch."""
+    sequence = np.random.SeedSequence(int(seed), spawn_key=(int(i),))
+    return int(sequence.generate_state(1)[0] >> 1)

@@ -16,7 +16,7 @@ import sys
 import tempfile
 import time
 
-from .cells import BENCH, ROOT, load_cell
+from .cells import BENCH, ROOT, check_pieces, load_cell
 
 PEAKS_FILE = BENCH / "harness" / "peaks.json"
 
@@ -112,9 +112,8 @@ def _traced_window(driver, seconds):
 def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         devices, peak: dict, precision: dict | None = None) -> dict:
     """Set up, measure, read, compare; the result as a dict."""
-    from .drivers import DRIVERS
     devices = devices[:cell.chips]
-    driver = DRIVERS[cell.traffic["driver"]](cell, seed, devices, precision)
+    driver = cell.driver.Driver(cell, seed, devices, precision)
     driver.setup()
     compiles = CompileCount()
     setup_s = time.perf_counter() - t_start
@@ -179,19 +178,25 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     return result
 
 
-def prepare(workload: str):
+def prepare(workload: str, root=ROOT):
     """Checks made before any work, then the compile cache: ``(cell,
-    devices, peak)``. Exits non-zero without a TPU, with fewer chips than
-    the cell asks for, with x64 on, or for a device kind with no peaks."""
+    devices, peak)``. Exits non-zero where a piece the cell names is
+    missing or lacks its interface, where the kernel is unknown, without a
+    TPU, with fewer chips than the cell asks for, with x64 on, or for a
+    device kind with no peaks."""
     # the benchmark's compile cache, at a fixed place in its checkout
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(root / ".jax_cache")
     import jax
+    cell = load_cell(workload, root)
+    sys.path.insert(0, str(root / "src"))
+    from .drivers import kernel_for
+    kernel_for(cell.config)
+    check_pieces(cell)
     devices = jax.devices()
     if devices[0].platform != "tpu":
         raise SystemExit(f"bench: no TPU: JAX's first device is "
                          f"{devices[0].platform!r}; the benchmark never "
                          f"runs on another platform")
-    cell = load_cell(workload)
     if len(devices) < cell.chips:
         raise SystemExit(f"bench: {workload} needs {cell.chips} chips, "
                          f"JAX sees {len(devices)}")
@@ -199,7 +204,6 @@ def prepare(workload: str):
         raise SystemExit("bench: x64 is on; the configurations are float32")
     peak = peak_for(devices[0].device_kind)
 
-    sys.path.insert(0, str(ROOT / "src"))
     from repro.compile_cache import enable_compile_cache
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     log(f"jax {jax.__version__}; {devices[0].device_kind}; "

@@ -26,6 +26,11 @@ keeps the rounding of the float32 products away from the solution.
   f(x) = (k(x, Z)·w) L⁻ᵀ (FᵀF + nλI)⁻¹ Fᵀy.
 
 Both the closed-form and the preconditioned-CG solvers converge to that f.
+
+This is the reference of the RBF configurations: a configuration names
+it by its ``reference`` key, and the drivers call it through the
+interface every reference module has (``harness.cells.REFERENCE_API``):
+``scores_and_predictions``, ``rel_rms``, ``max_gap`` and ``KERNELS``.
 """
 from __future__ import annotations
 
@@ -38,6 +43,8 @@ import scipy.linalg as sl
 # the smallest shift that stays representable against an O(1) diagonal.
 JITTER_REL = float(np.sqrt(np.finfo(np.float32).eps))
 BLOCK = 2**14            # rows per reference block
+# the uniform score landmarks below rely on the RBF kernel's unit diagonal
+KERNELS = ("rbf",)
 
 
 def rbf(A, B, bandwidth: float):
@@ -139,6 +146,17 @@ def predict(X, y, Xt, idx, ref_scores, lam: float, bandwidth: float):
     u = sl.cho_solve(sl.cho_factor(H, lower=True), b)
     Bt = np.asarray(_basis_block(Xt, Z, w32, Pt, bandwidth), np.float64)
     return Bt @ u
+
+
+def scores_and_predictions(config: dict, seed: int, X, y, Xt, idx):
+    """``(scores, predictions)`` of the fit with ``SketchConfig.seed =
+    seed`` whose Theorem-3 draw was ``idx``: the Theorem-4 scores of every
+    row of ``X`` and the predictions at the held-out rows ``Xt``."""
+    idx_s = score_landmarks(seed, X.shape[0], config["p_scores"])
+    ref_scores = scores(X, idx_s, config["lam"] * config["eps"],
+                        config["bandwidth"])
+    return ref_scores, predict(X, y, Xt, idx, ref_scores, config["lam"],
+                               config["bandwidth"])
 
 
 def rel_rms(a, b) -> float:

@@ -6,7 +6,7 @@ runs the same on a trace read from the chip and on a small recorded one
 in the tests:
 
     Trace(window=(start_ns, end_ns),
-          devices={plane: {"ops": [...]}},
+          devices={plane: {"ops": [...], "modules": [...]}},
           host=[...])
 
 where every list holds ``(start_ns, end_ns, name)``. ``window`` is the
@@ -14,7 +14,9 @@ benchmark's own ``bench.window`` span on the host; device events are
 clipped to it.
 
 On a TPU the profiler writes one plane per chip, ``/device:TPU:<i>``,
-whose ``XLA Ops`` line holds every operation the chip ran.
+whose ``XLA Ops`` line holds every operation the chip ran, and whose
+``XLA Modules`` line every run of a whole program, named by the program
+(``jit_score_pass(<id>)``).
 """
 from __future__ import annotations
 
@@ -25,12 +27,13 @@ import re
 WINDOW_SPAN = "bench.window"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 
 
 @dataclasses.dataclass
 class Trace:
     window: tuple            # (start_ns, end_ns) of the measured window
-    devices: dict            # plane name -> {"ops": [...]}
+    devices: dict            # plane -> {"ops": [...], "modules": [...]}
     host: list               # (start_ns, end_ns, name) on host threads
 
     @property
@@ -50,9 +53,12 @@ def read_xplane(log_dir: str) -> Trace:
     for plane in data.planes:
         if DEVICE_PLANE.match(plane.name):
             lines = {line.name: line for line in plane.lines}
-            ops = lines[OPS_LINE].events if OPS_LINE in lines else []
-            devices[plane.name] = {"ops": [(int(e.start_ns), int(e.end_ns),
-                                            e.name) for e in ops]}
+            devices[plane.name] = {
+                key: [(int(e.start_ns), int(e.end_ns), e.name)
+                      for e in (lines[line].events if line in lines
+                                else [])]
+                for key, line in (("ops", OPS_LINE),
+                                  ("modules", MODULES_LINE))}
         elif plane.name.startswith("/host:CPU"):
             for line in plane.lines:
                 for e in line.events:
@@ -74,6 +80,18 @@ def clip(events, window):
             if e > lo and s < hi]
 
 
+def whole_spans(trace: Trace, name: str) -> list:
+    """Host spans named ``name`` that lie wholly inside the window."""
+    lo, hi = trace.window
+    return [h for h in trace.host if h[2] == name and lo <= h[0]
+            and h[1] <= hi]
+
+
+def within(events, outer) -> list:
+    """The events that lie wholly inside the span ``outer``."""
+    return [e for e in events if outer[0] <= e[0] and e[1] <= outer[1]]
+
+
 def union(events) -> list:
     """Merged ``(start, end)`` intervals covered by any event."""
     merged = []
@@ -83,6 +101,14 @@ def union(events) -> list:
         else:
             merged.append([s, e])
     return [tuple(m) for m in merged]
+
+
+def program_runs(trace: Trace, planes, program: str) -> list:
+    """Every run on ``planes`` of the program named ``program``
+    (``jit_score_pass``, as ``jit_score_pass(<id>)`` or
+    ``jit_score_pass.<n>``)."""
+    return [m for p in planes for m in trace.devices[p].get("modules", [])
+            if re.sub(r"\.\d+$", "", m[2].split("(")[0]) == program]
 
 
 def busy_ns(trace: Trace, plane: str) -> int:

@@ -5,8 +5,8 @@
         --seconds 20 --trace 0
 
 The cell is found by name in ``BENCHMARK.json``; its configuration,
-traffic mix, limits and per-layer readers in the files under ``bench/``
-that carry those names. The last line of standard output is the result's
+traffic mix, driver, rows, reference, limits and per-layer readers in
+the files under ``bench/`` that carry those names. The last line of standard output is the result's
 JSON object. The run exits non-zero, printing no result, unless JAX's
 first device is a TPU and there are as many as the cell asks for.
 """

@@ -10,9 +10,10 @@ import sys
 
 import pytest
 
-from harness.cells import BENCH, ROOT, load_cell
-from harness.data import seed31
-from harness.main import peak_for
+from harness.cells import BENCH, ROOT, check_pieces, load_cell
+from harness.data import fit_seed, seed31
+from harness.drivers import kernel_for
+from harness.main import peak_for, prepare
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 WORKLOADS = [w["name"] for w in SPEC["workloads"]]
@@ -56,6 +57,102 @@ def test_cell_loads_with_every_piece(workload):
     reduced = next(c["reduced"] for c in SPEC["configs"]
                    if c["name"] == cell.config["name"])
     assert set(reduced) == set(cell.config["reduced"])
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in SPEC["configs"]])
+def test_every_config_names_pieces_that_resolve(config):
+    """Its rows, its reference and its kernel, each found and whole."""
+    workload = next(w["name"] for w in SPEC["workloads"]
+                    if w["config"] == config)
+    cell = load_cell(workload)
+    check_pieces(cell)
+    assert callable(cell.rows.make)
+    assert cell.config["kernel"] in cell.reference.KERNELS
+    assert type(kernel_for(cell.config)).__name__ == "RBFKernel"
+    assert kernel_for(cell.config).bandwidth == cell.config["bandwidth"]
+
+
+def test_linear_and_polynomial_kernels_are_the_programs():
+    from repro.core.kernels import LinearKernel, PolynomialKernel
+    assert kernel_for({"kernel": "linear"}) == LinearKernel()
+    poly = kernel_for({"kernel": "polynomial", "degree": 3, "offset": 0.5})
+    assert poly == PolynomialKernel(degree=3, offset=0.5)
+    assert kernel_for({"kernel": "poly", "degree": 3, "offset": 0.5}) == poly
+
+
+@pytest.fixture
+def copy(tmp_path, monkeypatch):
+    """``BENCHMARK.json`` and ``bench/`` copied, to break one piece in;
+    what ``prepare`` sets in the process is put back afterwards."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return tmp_path
+
+
+def _edit_json(path, **changes):
+    data = json.loads(path.read_text())
+    data.update(changes)
+    path.write_text(json.dumps(data))
+
+
+BROKEN = {
+    "driver": (lambda root: _edit_json(
+        root / "bench/traffic/serve_poisson.json", driver="no_such_driver"),
+        "no_such_driver.py, which is not there"),
+    "rows": (lambda root: _edit_json(
+        root / "bench/configs/susy_falkon.json", rows="no_such_rows"),
+        "no_such_rows.py, which is not there"),
+    "reference": (lambda root: _edit_json(
+        root / "bench/configs/susy_falkon.json",
+        reference="bench/harness/no_such_reference.py"),
+        "no_such_reference.py, which is not there"),
+    "reference_interface": (lambda root: (
+        (root / "bench/harness/bare.py").write_text("KERNELS = ('rbf',)\n"),
+        _edit_json(root / "bench/configs/susy_falkon.json",
+                   reference="bench/harness/bare.py")),
+        "lacks scores_and_predictions, rel_rms, max_gap"),
+    "driver_interface": (lambda root: (
+        root / "bench/drivers/serve_poisson.py").write_text(
+            "class Driver:\n    def setup(self):\n        pass\n"),
+        "lacks window, counters, check"),
+    "kernel": (lambda root: _edit_json(
+        root / "bench/configs/susy_falkon.json", kernel="no_such_kernel"),
+        "unknown kernel 'no_such_kernel'; known: \\['bernoulli', "),
+    "kernel_of_reference": (lambda root: _edit_json(
+        root / "bench/configs/susy_falkon.json", kernel="linear"),
+        "covers kernels \\['rbf'\\], not 'linear'"),
+}
+
+
+@pytest.mark.parametrize("piece", sorted(BROKEN))
+def test_broken_piece_stops_prepare_before_any_work(piece, copy,
+                                                    monkeypatch):
+    """``prepare`` stops on the piece, before it looks for a chip (which
+    the CPU would fail) and before any driver, rows or fit is made."""
+    break_it, message = BROKEN[piece]
+    break_it(copy)
+    made = []
+    monkeypatch.setattr(
+        "harness.drivers.CellDriver.__init__",
+        lambda *a, **k: made.append(a))
+    with pytest.raises(SystemExit, match=message):
+        prepare("susy_falkon.serve_poisson", root=copy)
+    assert not made
+
+
+def test_sound_pieces_reach_the_look_for_a_chip(copy):
+    with pytest.raises(SystemExit, match="no TPU"):
+        prepare("susy_falkon.serve_poisson", root=copy)
+
+
+def test_fit_seeds_differ_by_fit_and_by_run():
+    runs = (0, 5, 2**32 + 5, 2**33 + 5)
+    seeds = {fit_seed(s, i) for s in runs for i in range(4)}
+    assert len(seeds) == 16 and all(0 <= s < 2**31 for s in seeds)
+    assert fit_seed(5, 1) == fit_seed(5, 1)
 
 
 def test_seed_of_any_size_maps_into_31_bits():

@@ -9,7 +9,8 @@ from bench_tiny import CONTROL_SIZE, run_tiny
 from harness.cells import load_cell
 
 
-@pytest.mark.parametrize("workload", ["susy_falkon.serve_poisson"])
+@pytest.mark.parametrize("workload", ["susy_falkon.serve_poisson",
+                                      "susy_falkon.fit"])
 def test_control_is_not_correct(workload):
     """The program's bf16 data path (bf16 rows and kernel blocks, float32
     p x p solves) runs to its end and fails a limit, while the program at
@@ -28,3 +29,16 @@ def test_one_served_answer_altered_is_not_correct(monkeypatch):
     assert not res["correct"], res["checks"]
     gap = res["checks"]["served_max_gap"]
     assert gap["value"] > gap["limit"]
+
+
+@pytest.mark.parametrize("fault, fails", [
+    ("altered_prediction", "pred_max_gap"),
+    ("pcg_step_unchanged", "pred_rel_rms"),
+    ("half_rows", "pred_rel_rms"),
+])
+def test_fit_fault_is_not_correct(fault, fails, monkeypatch):
+    getattr(faults, f"plant_{fault}")(monkeypatch.setattr)
+    res = run_tiny("susy_falkon.fit")
+    assert not res["correct"], res["checks"]
+    check = res["checks"][fails]
+    assert check["value"] > check["limit"], res["checks"]

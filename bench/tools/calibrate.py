@@ -40,7 +40,6 @@ def main() -> int:
     ap.add_argument("--fault", nargs="+", default=None)
     args = ap.parse_args()
     cell, devices, _ = prepare(args.workload)
-    from harness.drivers import DRIVERS
     control = args.precision or cell.limits["control"]["precision"]
     if args.fault:
         import faults
@@ -48,17 +47,17 @@ def main() -> int:
     runs = [(s, None) for s in args.seeds] + \
         [(s, control) for s in args.control_seeds]
     for seed, precision in runs:
-        driver = DRIVERS[cell.traffic["driver"]](
-            cell, seed, devices[:cell.chips], precision)
-        driver.setup()
-        out = driver.window(args.seconds)
+        driver = cell.driver.Driver(cell, seed, devices[:cell.chips],
+                                    precision)
+        out, numbers = {}, {}
         try:
+            driver.setup()
+            out = driver.window(args.seconds)
             numbers = driver.check()
         except Exception as exc:              # noqa: BLE001 — reported
             numbers = {"error": repr(exc)}
         line = {"workload": cell.name, "seed": seed, "fault": args.fault,
-                "control": precision, "failed": out["failed"],
-                "attempted": out["attempted"], "numbers": numbers}
+                "control": precision, "window": out, "numbers": numbers}
         print(json.dumps(line), flush=True)
         del driver
     log("calibrate: done")

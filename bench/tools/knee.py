@@ -29,9 +29,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     cell, devices, _ = prepare(args.workload)
-    from harness.drivers import DRIVERS
-    driver = DRIVERS[cell.traffic["driver"]](cell, args.seed,
-                                             devices[:cell.chips])
+    driver = cell.driver.Driver(cell, args.seed, devices[:cell.chips])
     driver.setup()
     limit = cell.traffic["p95_limit_ms"]
     for rate in (float(r) for r in args.rates.split(",")):
